@@ -1,4 +1,4 @@
-"""Benchmark runner — one module per paper table/figure plus the roofline.
+"""Benchmark runner — one module per paper table/figure.
 
 Prints ``name,value,derived`` CSV rows (assignment format). ``--quick``
 shrinks sweeps; ``--only fig09`` runs a single module.
@@ -24,7 +24,6 @@ _NAMED = {
     "engine": "engine_step",
     "manager": "manager_round",
     "kernels": "kernels_micro",
-    "roofline": "roofline",
 }
 
 
@@ -59,6 +58,8 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args, _ = ap.parse_known_args()
 
+    from repro.launch.runtime import enable_compile_cache
+    enable_compile_cache()
     modules = discover()
     if args.only:
         if args.only not in modules:

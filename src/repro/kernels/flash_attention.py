@@ -5,8 +5,11 @@ Tiling: grid (B, H, n_q_blocks, n_k_blocks); the k-axis is the innermost
 (sequential) grid dimension, with running max / denominator / accumulator in
 VMEM scratch — the classic TPU flash schedule. Q/K/V tiles are VMEM-resident
 [block, head_dim] slabs; head_dim is expected MXU-aligned (128 multiples).
-GQA is handled in the K/V index maps (kv_head = q_head // group) so K/V
-tiles are fetched once per kv head, not per q head.
+The wrapper transposes [B, S, H, D] to head-major [B, H, S, D] so each
+block's two minor dims are (block, head_dim): a TPU block's last two dims
+must tile (8, 128) or span the array, which a (1, head_dim) slice of the
+head axis does not. GQA is handled in the K/V index maps (kv_head = q_head
+// group) so K/V tiles are fetched once per kv head, not per q head.
 
 Oracle: repro.kernels.ref.attention (tests sweep shapes/dtypes/causal/window).
 """
@@ -34,9 +37,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :]                      # [bq, d]
-    k = k_ref[0, :, 0, :]                      # [bk, d]
-    v = v_ref[0, :, 0, :]                      # [bk, d]
+    q = q_ref[...]                             # [bq, d]
+    k = k_ref[...]                             # [bk, d]
+    v = v_ref[...]                             # [bk, d]
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -67,7 +70,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ik == nk - 1)
     def _done():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -95,20 +98,22 @@ def flash_attention(
         _kernel, scale=scale, causal=causal, window=window,
         bq=bq, bk=bk, seq_q=s, seq_k=t,
     )
-    return pl.pallas_call(
+    q_spec = pl.BlockSpec((None, None, bq, d),
+                          lambda b_, h_, iq, ik: (b_, h_, iq, 0))
+    kv_spec = pl.BlockSpec((None, None, bk, d),
+                           lambda b_, h_, iq, ik: (b_, h_ // group, ik, 0))
+    out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, 1, d), lambda b_, h_, iq, ik: (b_, iq, h_, 0)),
-            pl.BlockSpec((1, bk, 1, d), lambda b_, h_, iq, ik: (b_, ik, h_ // group, 0)),
-            pl.BlockSpec((1, bk, 1, d), lambda b_, h_, iq, ik: (b_, ik, h_ // group, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, 1, d), lambda b_, h_, iq, ik: (b_, iq, h_, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, s, h, d), q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq,), jnp.float32),      # running max
             pltpu.VMEM((bq,), jnp.float32),      # running denominator
             pltpu.VMEM((bq, d), jnp.float32),    # output accumulator
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+      v.transpose(0, 2, 1, 3))
+    return out.transpose(0, 2, 1, 3)

@@ -4,7 +4,7 @@ Dispatch policy: the Pallas TPU kernels engage on TPU backends (or when
 REPRO_FORCE_PALLAS=1 requests interpret-mode execution, used by the kernel
 tests); everywhere else — CPU smoke tests and the 512-host-device dry-run —
 the jnp oracle executes, which also keeps `cost_analysis()` clean for the
-roofline pass.
+dry-run.
 """
 from __future__ import annotations
 
@@ -18,10 +18,7 @@ from . import ref
 def _use_pallas() -> bool:
     if os.environ.get("REPRO_FORCE_PALLAS") == "1":
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:

@@ -91,12 +91,12 @@ def _kernel(*args, page: int, group: int, bp: int, quant: bool):
 
     # validity: slot index within the sequence length, and the sub-page's
     # table entry mapped (>= 0) — padding columns and pool holes mask out
-    base = ib * span
-    slot = base + jax.lax.broadcasted_iota(jnp.int32, (kv, group, span), 2)
-    valid = slot < lengths_ref[b]
-    mapped = jnp.stack(
-        [table_ref[b, ib * bp + j] >= 0 for j in range(bp)])     # [bp]
-    valid &= jnp.repeat(mapped, page)[None, None, :]
+    col = jax.lax.broadcasted_iota(jnp.int32, (kv, group, span), 2)
+    valid = ib * span + col < lengths_ref[b]
+    # per-sub-page mask from scalar compares against an iota: Mosaic has
+    # no layout for a stacked [bp] bool vector broadcast to the span
+    for j in range(bp):
+        valid &= (col // page != j) | (table_ref[b, ib * bp + j] >= 0)
     s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_scr[...]                         # [kv, group]

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import COMPILER_PARAMS
-
 
 def _kernel(x_ref, a_ref, o_ref, h_scr, *, chunk: int):
     ic = pl.program_id(1)
@@ -60,7 +58,7 @@ def rglru(x: jax.Array, a: jax.Array, chunk: int = 128, interpret: bool = False)
         out_specs=pl.BlockSpec((1, chunk, w), lambda b_, ic: (b_, ic, 0)),
         out_shape=jax.ShapeDtypeStruct((b, t, w), x.dtype),
         scratch_shapes=[pltpu.VMEM((chunk + 1, w), jnp.float32)],
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import COMPILER_PARAMS
-
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *, chunk: int):
     ic = pl.program_id(2)
@@ -67,7 +65,7 @@ def rwkv6_wkv(r, k, v, w, u, chunk: int = 128, interpret: bool = False):
         out_specs=pl.BlockSpec((1, chunk, 1, dv), lambda b_, h_, ic: (b_, ic, h_, 0)),
         out_shape=jax.ShapeDtypeStruct((b, t, h, dv), r.dtype),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

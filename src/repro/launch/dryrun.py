@@ -10,8 +10,7 @@ For each cell this prints/records:
   - compiled.memory_analysis()  (per-device bytes: proves it fits)
   - compiled.cost_analysis()    (FLOPs / bytes for the roofline)
   - collective-op byte totals parsed from the optimized HLO
-and appends the result to a JSON ledger so the roofline benchmark and the
-perf loop read from it. Usage:
+and appends the result to a JSON ledger. Usage:
 
   PYTHONPATH=src python -m repro.launch.dryrun --arch granite-8b \
       --shape train_4k --mesh single --out results/dryrun.json

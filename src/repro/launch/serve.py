@@ -1,5 +1,5 @@
-"""Serving launcher: prefill + batched decode for --arch <id> (smoke scale on
-CPU), demonstrating the lowered serve path end-to-end.
+"""Serving launcher: prefill + batched greedy decode for --arch <id>
+(`serve` is the callable body; smoke scale on CPU, full width on a TPU).
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-14b --smoke \
       --batch 4 --prompt-len 32 --gen 16
@@ -13,11 +13,14 @@ from __future__ import annotations
 
 import argparse
 import time
+from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.launch.runtime import enable_compile_cache
 from repro.models import decode as D
 from repro.models import transformer as T
 
@@ -28,7 +31,7 @@ def run_runtime_layer(n_replicas: int, steps: int = 12) -> None:
 
     cfg = E.EngineConfig(n_replicas=n_replicas)
     state = E.init(cfg, jax.random.key(0))
-    arrivals = jnp.zeros((n_replicas,), jnp.int32).at[0].set(5).at[1].set(1)
+    arrivals = skewed_arrivals(n_replicas)
     # warmup step so the printed rate is steady-state, not trace+compile
     state, stats = E.step(cfg, state, arrivals)
     redirected = int(stats["redirected"])
@@ -47,6 +50,77 @@ def run_runtime_layer(n_replicas: int, steps: int = 12) -> None:
           f"utils={[round(float(u), 2) for u in stats['util']]}")
 
 
+def skewed_arrivals(n_replicas: int) -> jax.Array:
+    """Per-step arrivals with replica 0 hot and replica 1 warm — the load
+    that makes the harvesting runtime redirect."""
+    return jnp.zeros((n_replicas,), jnp.int32).at[0].set(5).at[1].set(1)
+
+
+class ServeRun(NamedTuple):
+    tokens: jax.Array     # [B, gen] int32 greedy tokens
+    finite: jax.Array     # [gen + 1] bool: logits finite after prefill and
+                          # after every decode step
+    prefill_hlo: str      # optimized HLO of the compiled prefill
+    compile_s: float      # prefill + decode-step compilation
+    prefill_s: float
+    decode_s: float
+
+
+def serve_inputs(cfg, batch: int, prompt_len: int, seed: int = 0):
+    """Seeded prompt for one prefill: (token ids or None, kwargs). Configs
+    with a stub frontend take seeded ``input_embeds`` / ``enc_embeds``."""
+    tokens = jax.random.randint(jax.random.key(seed + 1), (batch, prompt_len),
+                                0, cfg.vocab)
+    kwargs = {}
+    if cfg.frontend and not cfg.is_encdec:
+        kwargs["input_embeds"] = jax.random.normal(
+            jax.random.key(seed + 2), (batch, prompt_len, cfg.d_model),
+            jnp.float32)
+        tokens = None
+    if cfg.is_encdec:
+        kwargs["enc_embeds"] = jax.random.normal(
+            jax.random.key(seed + 3), (batch, cfg.enc_seq, cfg.d_model),
+            jnp.float32)
+    return tokens, kwargs
+
+
+def serve(cfg, params, batch: int, prompt_len: int, gen: int,
+          seed: int = 0) -> ServeRun:
+    """Prefill a seeded batch, then greedy-decode ``gen`` tokens.
+
+    Both programs compile before the clock starts (``compile_s``), and
+    each timing ends in `jax.block_until_ready`, so ``prefill_s`` and
+    ``decode_s`` are device time plus dispatch, not enqueue time."""
+    tok_arg, kwargs = serve_inputs(cfg, batch, prompt_len, seed)
+    prefill_fn = partial(D.prefill, cfg, max_len=prompt_len + gen)
+    t0 = time.perf_counter()
+    prefill = jax.jit(prefill_fn).lower(params, tok_arg, **kwargs).compile()
+    _, cache_shape = jax.eval_shape(prefill_fn, params, tok_arg, **kwargs)
+    step = jax.jit(partial(D.decode_step, cfg), donate_argnums=(1,)).lower(
+        params, cache_shape,
+        jax.ShapeDtypeStruct((batch,), jnp.int32)).compile()
+    compile_s = time.perf_counter() - t0
+
+    t0 = time.perf_counter()
+    logits, cache = jax.block_until_ready(prefill(params, tok_arg, **kwargs))
+    prefill_s = time.perf_counter() - t0
+
+    finite = [jnp.all(jnp.isfinite(logits))]
+    tok = jnp.argmax(logits, -1).astype(jnp.int32)
+    out = []
+    t0 = time.perf_counter()
+    for _ in range(gen):
+        out.append(tok)
+        logits, cache = step(params, cache, tok)
+        finite.append(jnp.all(jnp.isfinite(logits)))
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+    tokens, finite = jax.block_until_ready(
+        (jnp.stack(out, axis=1), jnp.stack(finite)))
+    decode_s = time.perf_counter() - t0
+    return ServeRun(tokens, finite, prefill.as_text(), compile_s, prefill_s,
+                    decode_s)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=configs.ARCH_NAMES)
@@ -59,37 +133,18 @@ def main():
                     help="also run the XBOF harvesting runtime layer")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = configs.smoke(args.arch) if args.smoke else configs.get(args.arch)
-    params = T.init_params(cfg, jax.random.key(args.seed))
+    params = jax.jit(partial(T.init_params, cfg))(jax.random.key(args.seed))
     b, s = args.batch, args.prompt_len
-    tokens = jax.random.randint(jax.random.key(1), (b, s), 0, cfg.vocab)
-    kwargs = {}
-    tok_arg = tokens
-    if cfg.frontend and not cfg.is_encdec:
-        kwargs["input_embeds"] = jax.random.normal(
-            jax.random.key(2), (b, s, cfg.d_model), jnp.float32)
-        tok_arg = None
-    if cfg.is_encdec:
-        kwargs["enc_embeds"] = jax.random.normal(
-            jax.random.key(3), (b, cfg.enc_seq, cfg.d_model), jnp.float32)
-
-    t0 = time.time()
-    logits, cache = D.prefill(cfg, params, tok_arg, max_len=s + args.gen, **kwargs)
-    print(f"prefill {b}x{s}: {time.time() - t0:.2f}s")
-
-    step = jax.jit(lambda c, t: D.decode_step(cfg, params, c, t))
-    out = []
-    tok = jnp.argmax(logits, -1).astype(jnp.int32)
-    t0 = time.time()
-    for _ in range(args.gen):
-        out.append(tok)
-        logits, cache = step(cache, tok)
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)
-    dt = time.time() - t0
-    gen = jnp.stack(out, axis=1)
-    print(f"decoded {args.gen} tokens/seq in {dt:.2f}s "
-          f"({b * args.gen / dt:.1f} tok/s)")
-    print("sample:", gen[0][:12].tolist())
+    run = serve(cfg, params, b, s, args.gen, seed=0)
+    print(f"compile: {run.compile_s:.2f}s")
+    print(f"prefill {b}x{s}: {run.prefill_s:.2f}s")
+    print(f"decoded {args.gen} tokens/seq in {run.decode_s:.2f}s "
+          f"({b * args.gen / run.decode_s:.1f} tok/s)")
+    print("sample:", run.tokens[0][:12].tolist())
+    if not bool(run.finite.all()):
+        raise SystemExit("non-finite logits")
 
     if args.replicas > 0:
         run_runtime_layer(args.replicas)

@@ -86,7 +86,7 @@ def probe_variants(cfg: ArchConfig, kind: str):
     loop INSTANCES). An unrolled probe at depth L instead measures
     header + L*body. Compiling a few (scanned, unrolled) shallow variants
     yields a linear system whose solution gives per-layer bodies, from which
-    the full-depth "true" cost is reconstructed (benchmarks/roofline.py).
+    the full-depth "true" cost is reconstructed.
 
     Returns [(variant_cfg, coeffs)] where coeffs maps unknown name ->
     multiplier; unknowns are "header" plus per-kind layer bodies. The solver

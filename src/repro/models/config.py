@@ -80,7 +80,7 @@ class ArchConfig:
     scan_unroll: bool = False       # unroll layer scans (roofline probes:
                                     # XLA cost analysis counts a while-loop
                                     # body ONCE; an unrolled probe exposes
-                                    # per-layer cost — see benchmarks/roofline)
+                                    # per-layer cost — see launch/specs.py)
 
     @property
     def head_dim(self) -> int:

@@ -60,7 +60,6 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import costs
@@ -1096,11 +1095,11 @@ def make_sharded_step(cfg: EngineConfig, mesh=None):
     state_specs = state_partition_specs(cfg)
     stats_specs = {k: (P() if k in _GLOBAL_STATS else P(SHARD_AXIS))
                    for k in _STAT_KEYS}
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_shard_step, cfg, SHARD_AXIS), mesh=mesh,
         in_specs=(state_specs, P(SHARD_AXIS)),
         out_specs=(state_specs, stats_specs),
-        check_rep=False)
+        check_vma=False)
 
     @partial(jax.jit, donate_argnums=(0,))
     def sharded_step(state: EngineState, arrivals: jax.Array):

@@ -76,6 +76,19 @@ class LinkAccountRun(NamedTuple):
     saw_spill: bool
 
 
+def check_link_account(step: int, budget, redirect, spill) -> None:
+    """The unified LINK_BW account invariant for one step: per replica,
+    redirect-command bytes + spill-page bytes never exceed the byte budget
+    (own + borrowed − lent), and neither debit is negative. Raises
+    RuntimeError on violation (fails a benchmark run and a test alike)."""
+    b, r, s = (np.asarray(x) for x in (budget, redirect, spill))
+    if not (r + s <= b + 1e-5).all() or (r < -1e-9).any() \
+            or (s < -1e-9).any():
+        raise RuntimeError(
+            f"LINK_BW account violated at step {step}: "
+            f"redirect {r} + spill {s} > budget {b}")
+
+
 def drive_link_account(
     cfg: E.EngineConfig,
     state: E.EngineState,
@@ -83,9 +96,7 @@ def drive_link_account(
     steps: int,
 ) -> LinkAccountRun:
     """Drive ``steps`` engine steps, enforcing the account invariant on
-    every one: per replica, redirect-command bytes + spill-page bytes must
-    not exceed the LINK_BW byte budget (own + borrowed − lent). Raises
-    RuntimeError on violation (fails a benchmark run and a test alike)."""
+    every one through `check_link_account`."""
     cmd_b = float(costs.REDIRECT_CMD_BYTES)
     red = spill = budget = 0.0
     cmd_saturated = saw_redirect = saw_spill = False
@@ -94,11 +105,7 @@ def drive_link_account(
         b = np.asarray(st["link_budget_bytes"])
         r = np.asarray(st["link_redirect_bytes"])
         s = np.asarray(st["link_spill_bytes"])
-        if not (r + s <= b + 1e-5).all() or (r < -1e-9).any() \
-                or (s < -1e-9).any():
-            raise RuntimeError(
-                f"LINK_BW account violated at step {i}: "
-                f"redirect {r} + spill {s} > budget {b}")
+        check_link_account(i, b, r, s)
         cmd_saturated |= bool((b[1] > 0) and (r[1] > b[1] - cmd_b))
         saw_redirect |= bool(r.sum() > 0)
         saw_spill |= bool(s.sum() > 0)

@@ -16,10 +16,10 @@ Four layers of guarantees:
   4. The topology-plane rewire (DESIGN.md §11) reproduces the PR 6
      two-level round BITWISE at depth 2: `hierarchical_exchange` on a flat
      topology equals `shard_exchange` value-for-value, and full engine
-     runs land the exact state+stats digests captured from the
-     pre-topology implementation.
+     runs match, leaf for leaf, a run with the exchange swapped back to
+     the pre-topology `shard_exchange`.
 """
-import hashlib
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -204,48 +204,62 @@ class TestShardExchangePrimitive:
 class TestDepth2TopologyParity:
     """Layer 4: the topology plane at depth 2 IS the PR 6 exchange.
 
-    The digests below were captured from the pre-topology engine (one
-    `mgr.shard_exchange` per rtype, priced at the since-retired
-    `cross_shard_link_bytes` constant)
-    by hashing every stat of every step plus every state leaf of three
-    fixed scenarios. The rewired engine must land them bitwise —
-    state-for-state behavioral identity, not approximate parity.
+    Each case runs the engine twice in-process: once as it stands, and once
+    with `topology.hierarchical_exchange` replaced by the pre-topology exchange —
+    one `manager.shard_exchange` per rtype over all shards. Every stat of
+    every step and every state leaf must match leaf-for-leaf: behavioural
+    identity, not approximate parity, and independent of the toolchain
+    that compiled it (a pinned hash of float state is not).
     """
 
-    # (cfg, arrivals, sha256[:16] of 5 steps' stats + final state)
     CASES = {
         "unmetered": (dict(n_replicas=8, n_shards=2, seq_slots=2,
                            shadow_slots=2, cross_shard=True),
-                      [6, 6, 6, 6, 0, 0, 0, 0],
-                      "f95ef6b2d3792cd9"),
+                      [6, 6, 6, 6, 0, 0, 0, 0]),
         "metered": (dict(n_replicas=8, n_shards=2, seq_slots=2,
                          shadow_slots=2, pages_per_replica=8, max_pages=8,
                          link_pages_per_step=1, cross_shard=True),
-                    [5, 5, 5, 5, 0, 0, 0, 0],
-                    "ccf8363f679e3cfe"),
+                    [5, 5, 5, 5, 0, 0, 0, 0]),
         "metered4": (dict(n_replicas=16, n_shards=4, link_pages_per_step=2,
                           trace_driven=True, cross_shard=True),
-                     [4, 2, 0, 0, 0, 3, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0],
-                     "d2f1b4484817942c"),
+                     [4, 2, 0, 0, 0, 3, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0]),
     }
 
     @staticmethod
-    def _digest(cfg, arr, steps=5):
+    def _pre_topology_exchange(spare, want, topology, overheads=None):
+        """The pre-topology exchange: ONE shard_exchange over all shards."""
+        assert topology == topo.flat(spare.shape[0])
+        oh = 0.0 if overheads is None else overheads[0]
+        g, r = mgr.shard_exchange(jnp.asarray(spare, jnp.float32),
+                                  jnp.asarray(want, jnp.float32), oh)
+        return g[None], r[None]
+
+    @staticmethod
+    def _trajectory(cfg, arr, step_fn, steps=5):
+        """Every stat of every step, then every final state leaf."""
         state = E.init(cfg, jax.random.key(0))
-        h = hashlib.sha256()
+        out = []
         for _ in range(steps):
-            state, stats = E.step(cfg, state, jnp.asarray(arr, jnp.int32))
-            for k in sorted(stats):
-                h.update(np.ascontiguousarray(
-                    np.asarray(stats[k])).tobytes())
-        for leaf in jax.tree.leaves(state):
-            h.update(np.ascontiguousarray(np.asarray(leaf)).tobytes())
-        return h.hexdigest()[:16]
+            state, stats = step_fn(state, jnp.asarray(arr, jnp.int32))
+            out += [np.asarray(stats[k]) for k in sorted(stats)]
+        return out + [np.asarray(x) for x in jax.tree.leaves(state)]
+
+    @staticmethod
+    def _assert_same(got, want):
+        assert len(got) == len(want)
+        for i, (g, w) in enumerate(zip(got, want)):
+            np.testing.assert_array_equal(g, w, err_msg=f"leaf {i}")
 
     @pytest.mark.parametrize("name", sorted(CASES))
-    def test_engine_matches_pr6_digest(self, name):
-        kw, arr, expect = self.CASES[name]
-        assert self._digest(E.EngineConfig(**kw), arr) == expect
+    def test_engine_matches_pr6_digest(self, name, monkeypatch):
+        kw, arr = self.CASES[name]
+        cfg = E.EngineConfig(**kw)
+        now = self._trajectory(cfg, arr, partial(E.step, cfg))
+        monkeypatch.setattr(topo, "hierarchical_exchange",
+                            self._pre_topology_exchange)
+        # a fresh jit, so the patched exchange is what gets traced
+        ref = self._trajectory(cfg, arr, jax.jit(partial(E._step_impl, cfg)))
+        self._assert_same(now, ref)
 
     def test_flat_hierarchical_exchange_is_shard_exchange_bitwise(self):
         rng = np.random.default_rng(3)
@@ -264,10 +278,13 @@ class TestDepth2TopologyParity:
     def test_explicit_single_enclosure_matches_flat(self):
         """shards_per_enclosure == n_shards is the same flat topology —
         the config knob cannot fork the depth-2 code path."""
-        kw, arr, expect = self.CASES["metered"]
-        cfg = E.EngineConfig(**kw)._replace(shards_per_enclosure=2)
-        assert E.shard_topology(cfg) == topo.flat(2)
-        assert self._digest(cfg, arr) == expect
+        kw, arr = self.CASES["metered"]
+        flat = E.EngineConfig(**kw)
+        one = flat._replace(shards_per_enclosure=2)
+        assert E.shard_topology(one) == topo.flat(2)
+        self._assert_same(
+            self._trajectory(one, arr, partial(E.step, one)),
+            self._trajectory(flat, arr, partial(E.step, flat)))
 
 
 class TestEnclosureGroupedTopology:
