@@ -38,6 +38,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..obs import export as obs_x
 from . import descriptors as d
 from . import harvest as hv
 
@@ -180,13 +181,15 @@ def fluid_transfer(
     surplus (row sums ≤ 1, draw ≤ 1) and each borrower receives at most its
     deficit — the property the conservation tests pin down.
     """
-    pledged = assist * surplus[:, None]                  # [l, b]
-    gross = jnp.sum(pledged, axis=0)
-    avail = gross / (1.0 + overhead)
-    used = jnp.minimum(avail, deficit)
-    draw = jnp.where(
-        gross > 0, used * (1.0 + overhead) / jnp.maximum(gross, _EPS), 0.0)
-    used_from = pledged * draw[None, :]
+    with obs_x.scope("fluid_transfer"):
+        pledged = assist * surplus[:, None]              # [l, b]
+        gross = jnp.sum(pledged, axis=0)
+        avail = gross / (1.0 + overhead)
+        used = jnp.minimum(avail, deficit)
+        draw = jnp.where(
+            gross > 0, used * (1.0 + overhead) / jnp.maximum(gross, _EPS),
+            0.0)
+        used_from = pledged * draw[None, :]
     return used, used_from
 
 
@@ -307,7 +310,7 @@ class ResourceManager:
     ) -> d.IdleResourceTable:
         """One full management round: loop the registered policies through
         trigger → publish → release → claim, then one per-rtype sync."""
-        with jax.named_scope("mgmt_round"):
+        with obs_x.scope("mgmt_round"):
             return self._round(table, inputs)
 
     def _round(
@@ -349,7 +352,8 @@ class ResourceManager:
             if pol.preserve_claims:
                 table = self._release_stale(table, pol, keep)
             if pol.claim_rounds > 0:
-                table = self._claim_sweeps(table, pol, util, borrow)
+                with obs_x.scope("claim_sweep"):
+                    table = self._claim_sweeps(table, pol, util, borrow)
             utils[pol.rtype] = util
             if amount is not None:
                 amounts[pol.rtype] = amount

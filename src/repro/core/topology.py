@@ -32,6 +32,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..obs import export as obs_x
 from . import manager as mgr
 
 # canonical level names, innermost boundary first: index 0 crosses between
@@ -171,7 +172,7 @@ def hierarchical_exchange(
     block = 1
     for lv, (gsize, oh) in enumerate(zip(topo.group_sizes, overheads)):
         block *= gsize
-        with jax.named_scope(f"hier_exchange/{topo.level_name(lv)}"):
+        with obs_x.scope("hier_exchange", topo.level_name(lv)):
             gr, rc = _block_exchange(sp, wt, oh, block)
         grants_l.append(gr)
         recv_l.append(rc)

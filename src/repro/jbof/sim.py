@@ -41,6 +41,7 @@ from repro.core import events as ev_m
 from repro.core import harvest as hv
 from repro.core import manager as mgr
 from repro.core import topology as topo
+from repro.obs import export as obs_x
 from repro.obs import metrics as obs_m
 from repro.obs import spans as obs_s
 from repro.telemetry import want as tele_want
@@ -897,7 +898,7 @@ def _window_step(state: SimState, arr, trace, *, plat: Platform,
     # pre-obs program (the bitwise pin in tests/test_obs.py relies on it).
     obs_state = state.obs
     if obs.enabled:
-        with jax.named_scope("obs_record"):
+        with obs_x.scope("obs_record"):
             ms, elog = state.obs
             ms = SIM_METRICS.record(ms, {
                 "miss": miss,
@@ -1089,226 +1090,233 @@ def simulate(
         cfg = dataclasses.replace(cfg or SimConfig(), **legacy)
     elif cfg is None:
         cfg = SimConfig()
-    window_s, warmup, traces = cfg.window_s, cfg.warmup, cfg.traces
-    telemetry, n_enclosures = cfg.telemetry, cfg.n_enclosures
-    fabric_federation, obs = cfg.fabric_federation, cfg.obs
+    with obs_x.annotate("xbof.sim.prepare"):
+        window_s, warmup, traces = cfg.window_s, cfg.warmup, cfg.traces
+        telemetry, n_enclosures = cfg.telemetry, cfg.n_enclosures
+        fabric_federation, obs = cfg.fabric_federation, cfg.obs
 
-    n = arrivals.shape[1]
-    wv = workload_vec(workloads)
-    trace_driven = traces is not None and plat.harvest_dram
-    tcfg = telemetry if trace_driven else _NO_TELEMETRY
-    want_frac = (static_want_frac(wv)
-                 if plat.harvest_dram and not trace_driven
-                 else jnp.zeros((n,), jnp.float32))
-    warmup = min(warmup, max(arrivals.shape[0] - 1, 0))
-    traces_x = (traces if trace_driven
-                else jnp.zeros((arrivals.shape[0], n, 1), jnp.uint32))
-    ev_arr = (ev_m.compile(cfg.events, arrivals.shape[0], n, n_enclosures)
-              if cfg.events else None)
-    use_ev = ev_arr is not None
-    revoked_hist = None
+        n = arrivals.shape[1]
+        wv = workload_vec(workloads)
+        trace_driven = traces is not None and plat.harvest_dram
+        tcfg = telemetry if trace_driven else _NO_TELEMETRY
+        want_frac = (static_want_frac(wv)
+                     if plat.harvest_dram and not trace_driven
+                     else jnp.zeros((n,), jnp.float32))
+        warmup = min(warmup, max(arrivals.shape[0] - 1, 0))
+        traces_x = (traces if trace_driven
+                    else jnp.zeros((arrivals.shape[0], n, 1), jnp.uint32))
+        ev_arr = (ev_m.compile(cfg.events, arrivals.shape[0], n, n_enclosures)
+                  if cfg.events else None)
+        use_ev = ev_arr is not None
 
-    if n_enclosures <= 1:
-        step = partial(_window_step, plat=plat, wv=wv, want_frac=want_frac,
-                       window_s=window_s, warmup=warmup,
-                       trace_driven=trace_driven, tcfg=tcfg, obs=obs)
+        if n_enclosures <= 1:
+            step = partial(_window_step, plat=plat, wv=wv, want_frac=want_frac,
+                           window_s=window_s, warmup=warmup,
+                           trace_driven=trace_driven, tcfg=tcfg, obs=obs)
 
-        def body(carry, x):
-            state, i = carry
-            if use_ev:
-                arr, trc, ne = x
-                state, out = step(state, arr, trc, step_idx=i, ev=ne)
-            else:
-                arr, trc = x
-                state, out = step(state, arr, trc, step_idx=i)
-            return (state, i + 1), out
-
-        xs = ((arrivals, traces_x, ev_m.node_view(ev_arr)) if use_ev
-              else (arrivals, traces_x))
-        (st, _), aux = jax.lax.scan(
-            body, (_init_state(plat, n, tcfg, obs), jnp.int32(0)), xs)
-        if use_ev:
-            miss_hist, borrowed_hist, spare_hist, rev = aux
-            revoked_hist = rev.astype(jnp.float32)
-        else:
-            miss_hist, borrowed_hist, spare_hist = aux
-        energy = st.energy_j
-        host_busy = st.host_busy
-        obs_ms_el = st.obs
-        fabric_log = None
-    else:
-        e = n_enclosures
-        if n % e:
-            raise ValueError(
-                f"n_enclosures={e} must divide the {n} SSDs evenly")
-        nl = n // e
-        st0 = jax.tree.map(
-            lambda a: jnp.stack([a] * e), _init_state(plat, nl, tcfg, obs))
-        wv_e = jax.tree.map(lambda a: a.reshape(e, nl), wv)
-        wf_e = want_frac.reshape(e, nl)
-        xg0 = FabricIn(*(jnp.zeros((e,), jnp.float32) for _ in range(4)))
-        ftopo = topo.flat(e)
-        arr_e = arrivals.reshape(arrivals.shape[0], e, nl, -1)
-        trc_e = traces_x.reshape(traces_x.shape[0], e, nl, -1)
-        # fabric-tier grant events ride their own single-lane log in the
-        # outer carry (the vmapped per-enclosure logs only see level 0)
-        use_flog = obs.enabled and fabric_federation
-        price_p = float(costs.tier_link_bytes(
-            desc.PROCESSOR, extra_hops=plat.fabric_extra_hops))
-        price_s = float(costs.tier_link_bytes(
-            desc.DRAM,
-            cmd_bytes=plat.remote_lookup_bytes * plat.payload_comp_ratio,
-            extra_hops=plat.fabric_extra_hops))
-
-        if use_ev:
-            ne_e = jax.tree.map(
-                lambda a: a.reshape(a.shape[0], e, nl),
-                ev_m.node_view(ev_arr))
-
-        def body(carry, x):
-            if use_flog:
-                state, i, xg, flog = carry
-            else:
-                state, i, xg = carry
-            if use_ev:
-                arr, trc, ne, dr = x
-                # an enclosure dropping off the fabric invalidates its
-                # standing inbound/outbound fabric grants; zeroing the
-                # CARRY makes the tally tick exactly at the transition
-                rev_fab = sum(
-                    jnp.sum(jnp.where(dr, a, 0.0)) for a in xg)
-                xg = FabricIn(*(jnp.where(dr, 0.0, a) for a in xg))
-            else:
-                arr, trc = x
-
-            def one(s, a, t, w, wf, fab, ne1=None):
-                return _window_step(
-                    s, a, t, plat=plat, wv=w, want_frac=wf,
-                    window_s=window_s, step_idx=i, warmup=warmup,
-                    trace_driven=trace_driven, tcfg=tcfg, fabric=fab,
-                    obs=obs, ev=ne1)
-
-            if use_ev:
-                state, (miss, bseg, sspare, fout, rev) = jax.vmap(one)(
-                    state, arr, trc, wv_e, wf_e, xg, ne)
-                rev_node = jnp.sum(rev).astype(jnp.float32)
-                # a dropped enclosure neither publishes upward nor draws
-                # back from the fabric
-                fout = FabricOut(*(
-                    jnp.where(dr, 0.0, a) for a in fout))
-            else:
-                state, (miss, bseg, sspare, fout) = jax.vmap(one)(
-                    state, arr, trc, wv_e, wf_e, xg)
-            if fabric_federation:
-                # fabric level of the topology plane: settle the
-                # enclosures' residuals with the SAME exchange the engine
-                # and the intra-enclosure rounds run; grants hold for one
-                # management interval, like the local descriptor tables
-                gp, rp = topo.hierarchical_exchange(
-                    fout.proc_spare, fout.proc_want, ftopo)
-                gs, rs = topo.hierarchical_exchange(
-                    fout.seg_spare, fout.seg_want, ftopo)
+            def body(carry, x):
+                state, i = carry
                 if use_ev:
-                    # exactly the dropped block's cross-level grants die;
-                    # grants between surviving enclosures are untouched
-                    gp, rel_p = topo.invalidate_block_grants(gp, dr)
-                    gs, rel_s = topo.invalidate_block_grants(gs, dr)
-                    rp = jnp.where(dr[None, :], 0.0, rp)
-                    rs = jnp.where(dr[None, :], 0.0, rs)
-                    rev_fab = rev_fab + rel_p + rel_s
-                xg_new = FabricIn(
-                    proc_in=jnp.sum(rp, axis=0),
-                    proc_out=jnp.sum(gp, axis=(0, 2)),
-                    seg_in=jnp.sum(rs, axis=0),
-                    seg_out=jnp.sum(gs, axis=(0, 2)))
-                do = (i % plat.mgmt_interval) == 0
-                xg = jax.tree.map(
-                    lambda a, b: jnp.where(do, b, a), xg, xg_new)
-                if use_flog:
-                    # log only the grants that actually apply (mgmt gate);
-                    # lender/borrower columns carry ENCLOSURE ids
-                    for grants, rt, pr in ((gp[0], desc.PROCESSOR, price_p),
-                                           (gs[0], desc.DRAM, price_s)):
-                        rows, gmask = obs_s.grant_event_rows(
-                            grants, rtype=rt, level=2, t=i,
-                            code=obs_s.FABRIC_GRANT, price=pr)
-                        flog = obs_s.append(flog, rows, gmask & do)
-            out = (miss, bseg, sspare)
-            if use_ev:
-                out = out + (rev_node + rev_fab,)
-            if use_flog:
-                return (state, i + 1, xg, flog), out
-            return (state, i + 1, xg), out
+                    arr, trc, ne = x
+                    state, out = step(state, arr, trc, step_idx=i, ev=ne)
+                else:
+                    arr, trc = x
+                    state, out = step(state, arr, trc, step_idx=i)
+                return (state, i + 1), out
 
-        carry0 = ((st0, jnp.int32(0), xg0,
-                   obs_s.make_log(obs.event_capacity)) if use_flog
-                  else (st0, jnp.int32(0), xg0))
-        xs = ((arr_e, trc_e, ne_e, ev_arr.drop) if use_ev
-              else (arr_e, trc_e))
-        carry1, aux = jax.lax.scan(body, carry0, xs)
-        if use_ev:
-            miss_hist, borrowed_hist, spare_hist, revoked_hist = aux
+            xs = ((arrivals, traces_x, ev_m.node_view(ev_arr)) if use_ev
+                  else (arrivals, traces_x))
+            carry0 = (_init_state(plat, n, tcfg, obs), jnp.int32(0))
         else:
-            miss_hist, borrowed_hist, spare_hist = aux
-        st = carry1[0]
-        fabric_log = carry1[3] if use_flog else None
-        miss_hist = miss_hist.reshape(miss_hist.shape[0], n)
-        borrowed_hist = borrowed_hist.reshape(borrowed_hist.shape[0], n)
-        spare_hist = spare_hist.reshape(spare_hist.shape[0], n)
-        energy = jnp.sum(st.energy_j)
-        host_busy = st.host_busy  # [E] — one host DPU per enclosure
-        fl = lambda a: a.reshape(n)
-        st = st._replace(
-            served_r=fl(st.served_r), served_w=fl(st.served_w),
-            proc_busy=fl(st.proc_busy), flash_busy=fl(st.flash_busy),
-            flash_written=fl(st.flash_written), lat_sum=fl(st.lat_sum),
-            cmd_count=fl(st.cmd_count), log_commits=fl(st.log_commits),
-            cxl_bytes=fl(st.cxl_bytes), borrowed_seg=fl(st.borrowed_seg),
-            borrowed_far=fl(st.borrowed_far))
-        # collapse the vmapped [E, local, ...] obs leaves to the canonical
-        # layout: node lanes -> [n], scalar lanes -> [E], one log lane per
-        # enclosure (decode offsets the local node ids by lane * nl)
-        obs_ms_el = obs_m.merge_lead(st.obs) if obs.enabled else None
+            e = n_enclosures
+            if n % e:
+                raise ValueError(
+                    f"n_enclosures={e} must divide the {n} SSDs evenly")
+            nl = n // e
+            st0 = jax.tree.map(
+                lambda a: jnp.stack([a] * e), _init_state(plat, nl, tcfg, obs))
+            wv_e = jax.tree.map(lambda a: a.reshape(e, nl), wv)
+            wf_e = want_frac.reshape(e, nl)
+            xg0 = FabricIn(*(jnp.zeros((e,), jnp.float32) for _ in range(4)))
+            ftopo = topo.flat(e)
+            arr_e = arrivals.reshape(arrivals.shape[0], e, nl, -1)
+            trc_e = traces_x.reshape(traces_x.shape[0], e, nl, -1)
+            # fabric-tier grant events ride their own single-lane log in the
+            # outer carry (the vmapped per-enclosure logs only see level 0)
+            use_flog = obs.enabled and fabric_federation
+            price_p = float(costs.tier_link_bytes(
+                desc.PROCESSOR, extra_hops=plat.fabric_extra_hops))
+            price_s = float(costs.tier_link_bytes(
+                desc.DRAM,
+                cmd_bytes=plat.remote_lookup_bytes * plat.payload_comp_ratio,
+                extra_hops=plat.fabric_extra_hops))
 
-    t_total = (arrivals.shape[0] - warmup) * window_s
-    total = st.served_r + st.served_w
-    day_s = 86400.0
-    proc_cap_rate = plat.ssd_config.proc_clocks_per_s / ssd.CLOCK_HZ
-    rings = {"borrowed_seg": borrowed_hist, "spare_seg": spare_hist}
-    if revoked_hist is not None:
-        rings["revoked_grants"] = revoked_hist
-    obs_out = None
-    if obs.enabled:
-        ms, elog = obs_ms_el
-        id_stride = n // n_enclosures if n_enclosures > 1 else 0
-        records, dropped = obs_s.decode(elog, id_stride=id_stride)
-        if fabric_log is not None:
-            frecs, fdrop = obs_s.decode(fabric_log)
-            records = sorted(records + frecs,
-                             key=lambda r: (r["t"], r["lane"]))
-            dropped += fdrop
-        obs_out = {
-            "metrics": SIM_METRICS.history(ms),
-            "totals": SIM_METRICS.totals(ms),
-            "events": records,
-            "events_dropped": dropped,
-        }
-    return SimResult(
-        throughput_bps=total / t_total,
-        read_bps=st.served_r / t_total,
-        write_bps=st.served_w / t_total,
-        latency_s=st.lat_sum / jnp.maximum(st.cmd_count, 1.0),
-        proc_util=(st.proc_busy / (proc_cap_rate * t_total)) if plat.cores
-        else jnp.zeros_like(total),
-        flash_util=st.flash_busy / t_total,
-        miss_ratio=miss_hist[-1],
-        dwpd=(st.flash_written / t_total) * day_s / (ssd.SSD_CAPACITY_TB * 1e12),
-        energy_j=energy,
-        host_util=host_busy / t_total,
-        log_commits=st.log_commits,
-        cxl_bytes=st.cxl_bytes,
-        borrowed_seg=st.borrowed_seg,
-        borrowed_far=st.borrowed_far,
-        rings=rings,
-        obs=obs_out,
-    )
+            if use_ev:
+                ne_e = jax.tree.map(
+                    lambda a: a.reshape(a.shape[0], e, nl),
+                    ev_m.node_view(ev_arr))
+
+            def body(carry, x):
+                if use_flog:
+                    state, i, xg, flog = carry
+                else:
+                    state, i, xg = carry
+                if use_ev:
+                    arr, trc, ne, dr = x
+                    # an enclosure dropping off the fabric invalidates its
+                    # standing inbound/outbound fabric grants; zeroing the
+                    # CARRY makes the tally tick exactly at the transition
+                    rev_fab = sum(
+                        jnp.sum(jnp.where(dr, a, 0.0)) for a in xg)
+                    xg = FabricIn(*(jnp.where(dr, 0.0, a) for a in xg))
+                else:
+                    arr, trc = x
+
+                def one(s, a, t, w, wf, fab, ne1=None):
+                    return _window_step(
+                        s, a, t, plat=plat, wv=w, want_frac=wf,
+                        window_s=window_s, step_idx=i, warmup=warmup,
+                        trace_driven=trace_driven, tcfg=tcfg, fabric=fab,
+                        obs=obs, ev=ne1)
+
+                if use_ev:
+                    state, (miss, bseg, sspare, fout, rev) = jax.vmap(one)(
+                        state, arr, trc, wv_e, wf_e, xg, ne)
+                    rev_node = jnp.sum(rev).astype(jnp.float32)
+                    # a dropped enclosure neither publishes upward nor draws
+                    # back from the fabric
+                    fout = FabricOut(*(
+                        jnp.where(dr, 0.0, a) for a in fout))
+                else:
+                    state, (miss, bseg, sspare, fout) = jax.vmap(one)(
+                        state, arr, trc, wv_e, wf_e, xg)
+                if fabric_federation:
+                    # fabric level of the topology plane: settle the
+                    # enclosures' residuals with the SAME exchange the engine
+                    # and the intra-enclosure rounds run; grants hold for one
+                    # management interval, like the local descriptor tables
+                    gp, rp = topo.hierarchical_exchange(
+                        fout.proc_spare, fout.proc_want, ftopo)
+                    gs, rs = topo.hierarchical_exchange(
+                        fout.seg_spare, fout.seg_want, ftopo)
+                    if use_ev:
+                        # exactly the dropped block's cross-level grants die;
+                        # grants between surviving enclosures are untouched
+                        gp, rel_p = topo.invalidate_block_grants(gp, dr)
+                        gs, rel_s = topo.invalidate_block_grants(gs, dr)
+                        rp = jnp.where(dr[None, :], 0.0, rp)
+                        rs = jnp.where(dr[None, :], 0.0, rs)
+                        rev_fab = rev_fab + rel_p + rel_s
+                    xg_new = FabricIn(
+                        proc_in=jnp.sum(rp, axis=0),
+                        proc_out=jnp.sum(gp, axis=(0, 2)),
+                        seg_in=jnp.sum(rs, axis=0),
+                        seg_out=jnp.sum(gs, axis=(0, 2)))
+                    do = (i % plat.mgmt_interval) == 0
+                    xg = jax.tree.map(
+                        lambda a, b: jnp.where(do, b, a), xg, xg_new)
+                    if use_flog:
+                        # log only the grants that actually apply (mgmt gate);
+                        # lender/borrower columns carry ENCLOSURE ids
+                        for grants, rt, pr in (
+                                (gp[0], desc.PROCESSOR, price_p),
+                                (gs[0], desc.DRAM, price_s)):
+                            rows, gmask = obs_s.grant_event_rows(
+                                grants, rtype=rt, level=2, t=i,
+                                code=obs_s.FABRIC_GRANT, price=pr)
+                            flog = obs_s.append(flog, rows, gmask & do)
+                out = (miss, bseg, sspare)
+                if use_ev:
+                    out = out + (rev_node + rev_fab,)
+                if use_flog:
+                    return (state, i + 1, xg, flog), out
+                return (state, i + 1, xg), out
+
+            carry0 = ((st0, jnp.int32(0), xg0,
+                       obs_s.make_log(obs.event_capacity)) if use_flog
+                      else (st0, jnp.int32(0), xg0))
+            xs = ((arr_e, trc_e, ne_e, ev_arr.drop) if use_ev
+                  else (arr_e, trc_e))
+
+    with obs_x.annotate("xbof.sim.scan"):
+        carry1, aux = jax.lax.scan(body, carry0, xs)
+
+    with obs_x.annotate("xbof.sim.finish"):
+        st, revoked_hist = carry1[0], None
+        if n_enclosures <= 1:
+            if use_ev:
+                miss_hist, borrowed_hist, spare_hist, rev = aux
+                revoked_hist = rev.astype(jnp.float32)
+            else:
+                miss_hist, borrowed_hist, spare_hist = aux
+            energy = st.energy_j
+            host_busy = st.host_busy
+            obs_ms_el = st.obs
+            fabric_log = None
+        else:
+            if use_ev:
+                miss_hist, borrowed_hist, spare_hist, revoked_hist = aux
+            else:
+                miss_hist, borrowed_hist, spare_hist = aux
+            fabric_log = carry1[3] if use_flog else None
+            miss_hist = miss_hist.reshape(miss_hist.shape[0], n)
+            borrowed_hist = borrowed_hist.reshape(borrowed_hist.shape[0], n)
+            spare_hist = spare_hist.reshape(spare_hist.shape[0], n)
+            energy = jnp.sum(st.energy_j)
+            host_busy = st.host_busy  # [E] — one host DPU per enclosure
+            fl = lambda a: a.reshape(n)
+            st = st._replace(
+                served_r=fl(st.served_r), served_w=fl(st.served_w),
+                proc_busy=fl(st.proc_busy), flash_busy=fl(st.flash_busy),
+                flash_written=fl(st.flash_written), lat_sum=fl(st.lat_sum),
+                cmd_count=fl(st.cmd_count), log_commits=fl(st.log_commits),
+                cxl_bytes=fl(st.cxl_bytes), borrowed_seg=fl(st.borrowed_seg),
+                borrowed_far=fl(st.borrowed_far))
+            # collapse the vmapped [E, local, ...] obs leaves to the canonical
+            # layout: node lanes -> [n], scalar lanes -> [E], one log lane per
+            # enclosure (decode offsets the local node ids by lane * nl)
+            obs_ms_el = obs_m.merge_lead(st.obs) if obs.enabled else None
+
+        t_total = (arrivals.shape[0] - warmup) * window_s
+        total = st.served_r + st.served_w
+        day_s = 86400.0
+        proc_cap_rate = plat.ssd_config.proc_clocks_per_s / ssd.CLOCK_HZ
+        rings = {"borrowed_seg": borrowed_hist, "spare_seg": spare_hist}
+        if revoked_hist is not None:
+            rings["revoked_grants"] = revoked_hist
+        obs_out = None
+        if obs.enabled:
+            ms, elog = obs_ms_el
+            id_stride = n // n_enclosures if n_enclosures > 1 else 0
+            records, dropped = obs_s.decode(elog, id_stride=id_stride)
+            if fabric_log is not None:
+                frecs, fdrop = obs_s.decode(fabric_log)
+                records = sorted(records + frecs,
+                                 key=lambda r: (r["t"], r["lane"]))
+                dropped += fdrop
+            obs_out = {
+                "metrics": SIM_METRICS.history(ms),
+                "totals": SIM_METRICS.totals(ms),
+                "events": records,
+                "events_dropped": dropped,
+            }
+        return SimResult(
+            throughput_bps=total / t_total,
+            read_bps=st.served_r / t_total,
+            write_bps=st.served_w / t_total,
+            latency_s=st.lat_sum / jnp.maximum(st.cmd_count, 1.0),
+            proc_util=(st.proc_busy / (proc_cap_rate * t_total)) if plat.cores
+            else jnp.zeros_like(total),
+            flash_util=st.flash_busy / t_total,
+            miss_ratio=miss_hist[-1],
+            dwpd=(st.flash_written / t_total) * day_s
+            / (ssd.SSD_CAPACITY_TB * 1e12),
+            energy_j=energy,
+            host_util=host_busy / t_total,
+            log_commits=st.log_commits,
+            cxl_bytes=st.cxl_bytes,
+            borrowed_seg=st.borrowed_seg,
+            borrowed_far=st.borrowed_far,
+            rings=rings,
+            obs=obs_out,
+        )

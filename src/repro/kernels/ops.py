@@ -12,6 +12,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from ..obs import export as obs_x
 from . import ref
 
 
@@ -44,7 +45,7 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths,
     """Pass `k_scale`/`v_scale` ([P] f32) when the pool holds int8 codes —
     the kernel dequantizes in VMEM right before the dot (ref path folds the
     scales into scores/weights); omit them for fp pools."""
-    with jax.named_scope("paged_attention"):
+    with obs_x.scope("paged_attention"):
         if _use_pallas() and q.shape[-1] % 128 == 0:
             from .paged_attention import paged_attention as pa
             return pa(q, k_pool, v_pool, page_table, lengths,

@@ -1,18 +1,8 @@
 """Unified observability plane (DESIGN.md §12).
 
 In-scan metric rings (`metrics`), grant-lifecycle event logs (`spans`),
-and host-side JSON-lines / perfetto export (`export`) shared by the
-serving engine and the JBOF sim.
+and host-side JSON-lines / perfetto export with the profiler span registry
+(`export`), shared by the serving engine and the JBOF sim. Import the
+module you need: `spans` imports `repro.core`, and `core.manager` imports
+`export`, so the package imports none of them itself.
 """
-
-from .metrics import MetricSet, MetricsState, MetricSpec, ObsConfig, merge_lead
-from .spans import EventLog, append, decode, grant_event_rows, make_log, \
-    table_event_rows
-from .export import annotate, scope, to_perfetto, write_report
-
-__all__ = [
-    "MetricSet", "MetricsState", "MetricSpec", "ObsConfig", "merge_lead",
-    "EventLog", "append", "decode", "grant_event_rows", "make_log",
-    "table_event_rows",
-    "annotate", "scope", "to_perfetto", "write_report",
-]

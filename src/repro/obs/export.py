@@ -7,34 +7,87 @@ thread track per node, "X" complete events for grant lifetimes
 grants, and "C" counter tracks for every ring metric. Timestamps are
 window indices scaled by `window_us`.
 
-`annotate` / `scope` wrap the device-profiler hooks so Pallas-kernel and
-management-round hot paths line up with logical phases in a captured
-device profile; both degrade to no-ops when the profiler is absent.
+`scope` (a device scope, `jax.named_scope`) and `annotate` (a host span,
+`jax.profiler.TraceAnnotation`) are the only way the program names a span
+in a profile. Each name must be in `SPANS`, which says what reads it: a
+per-layer metric of the benchmark (`bench/metrics/<metric>.py`), or its
+operator use. A name missing there raises, so a renamed span fails loudly
+instead of leaving a metric with nothing to read.
+
+This module imports no other part of `repro`, so `core/` can import it.
 """
 
 from __future__ import annotations
 
 import json
-from contextlib import nullcontext
+from typing import NamedTuple
 
 import jax
 import numpy as np
+from jax._src import cache_key as _cache_key
 
 # Lifecycle pairing: an opener event and the closer that ends its span.
 _SPAN_PAIRS = {"claim": "release", "publish": "withdraw"}
 
 
+class Span(NamedTuple):
+    name: str
+    kind: str       # "device" (`scope`) or "host" (`annotate`)
+    read_by: tuple  # per-layer metrics that read it, or ("operator: ...",)
+
+
+SPANS = {s.name: s for s in (
+    # device scopes: op self time by name stack (`bench/trace_reduce.py`)
+    Span("mgmt_round", "device",
+         ("mgmt_round_ms.engine", "mgmt_round_us.sim")),
+    Span("claim_sweep", "device", ("claim_sweep_us.sim",)),
+    Span("fluid_transfer", "device", ("fluid_transfer_us.sim",)),
+    Span("hier_exchange", "device",
+         ("hier_exchange_ms.engine", "fabric_exchange_us.sim")),
+    Span("route", "device", ("route_admit_ms.engine",)),
+    Span("admit", "device", ("route_admit_ms.engine",)),
+    Span("kv_append", "device", ("kv_append_ms.engine",)),
+    Span("paged_attention", "device", ("paged_attention_roofline.engine",)),
+    Span("obs_record", "device",
+         ("operator: the observability plane's own cost in a profile",)),
+    # host spans of `jbof.sim.simulate`: device idle time they overlap
+    Span("xbof.sim.prepare", "host", ("prepare_idle_s.sim",)),
+    Span("xbof.sim.scan", "host", ("scan_build_idle_s.sim",)),
+    Span("xbof.sim.finish", "host",
+         ("operator: result assembly and obs decode after the scan",)),
+)}
+
+
+def _cache_key_salt() -> str:
+    return "repro.obs.export.SPANS=" + ",".join(
+        f"{s.kind}:{s.name}" for s in SPANS.values())
+
+
+# JAX's persistent-cache key leaves op metadata out, so an executable
+# compiled from code with other scopes would be loaded with their names and
+# a profile would attribute its time to them. The span set salts the key
+# (JAX's `cache_key.custom_hook`), keeping such executables apart.
+_cache_key.custom_hook = _cache_key_salt
+
+
+def _registered(name: str, kind: str) -> None:
+    span = SPANS.get(name)
+    if span is None or span.kind != kind:
+        raise KeyError(f"{kind} span {name!r} is not registered in "
+                       f"repro.obs.export.SPANS")
+
+
 def annotate(name: str):
-    """Host-side profiler annotation (`jax.profiler.TraceAnnotation`)."""
-    prof = getattr(jax, "profiler", None)
-    ta = getattr(prof, "TraceAnnotation", None) if prof is not None else None
-    return ta(name) if ta is not None else nullcontext()
+    """Host span on the profiler's clock (`jax.profiler.TraceAnnotation`)."""
+    _registered(name, "host")
+    return jax.profiler.TraceAnnotation(name)
 
 
-def scope(name: str):
-    """Trace-compatible named scope for jitted code (`jax.named_scope`)."""
-    ns = getattr(jax, "named_scope", None)
-    return ns(name) if ns is not None else nullcontext()
+def scope(name: str, part: str | None = None):
+    """Device scope for traced code (`jax.named_scope`): ``name``, or
+    ``name/part`` where one scope has several parts (the exchange levels)."""
+    _registered(name, "device")
+    return jax.named_scope(name if part is None else f"{name}/{part}")
 
 
 def metrics_jsonl(history: dict, totals: dict | None = None) -> str:

@@ -68,6 +68,7 @@ from repro.core import loadbalance as lb
 from repro.core import manager as mgr
 from repro.core import topology as topo
 from repro.kernels import ops as kops
+from repro.obs import export as obs_x
 from repro.obs import metrics as obs_m
 from repro.obs import spans as obs_s
 from repro.telemetry import reclaim as tele_reclaim
@@ -521,9 +522,10 @@ def _decode_all(cfg: EngineConfig, state: EngineState, dram_lenders,
     length_before = pool.seq_len
     # append returns the offsite page grants of this step per home replica
     # (the LINK_BW debit for spill traffic) — no before/after offsite scan
-    pool, spill_pages = kvp.append_tokens(pool, k_t, v_t, active,
-                                          dram_lenders,
-                                          spill_budget=spill_budget)
+    with obs_x.scope("kv_append"):
+        pool, spill_pages = kvp.append_tokens(pool, k_t, v_t, active,
+                                              dram_lenders,
+                                              spill_budget=spill_budget)
 
     p = cfg.pages_per_replica
     k_flat = pool.k.reshape(r * p, cfg.page, cfg.kv_heads, cfg.head_dim)
@@ -724,7 +726,8 @@ def _shard_step(cfg: EngineConfig, axis, state: EngineState,
     prev_table = state.table  # obs: grant events = round's table diff
     table = manager.round(state.table, inputs)
     state = state._replace(table=table)
-    kept, sent = _route(cfg, state, arrivals)
+    with obs_x.scope("route"):
+        kept, sent = _route(cfg, state, arrivals)
     # DRAM descriptors are amount-gated capacity, never claimed: a replica
     # lends KV pages iff its descriptor is live with pages above threshold.
     # The slot comes from the manager's policy (slot_mask), never a literal
@@ -756,14 +759,16 @@ def _shard_step(cfg: EngineConfig, axis, state: EngineState,
         # (the command stream is issued before decode spills): redirects
         # beyond the byte budget stay home and retry via the queue —
         # backpressure, the same rule as a denied spill
-        cmd_b = float(costs.REDIRECT_CMD_BYTES)
-        red_cap = jnp.floor(budget_bytes / cmd_b).astype(jnp.int32)
-        cum = jnp.cumsum(sent, axis=1)
-        capped = jnp.maximum(
-            jnp.minimum(cum, red_cap[:, None]) - (cum - sent), 0)
-        kept = kept + jnp.sum(sent - capped, axis=1)
-        sent = capped
-        redirect_bytes = jnp.sum(sent, axis=1).astype(jnp.float32) * cmd_b
+        with obs_x.scope("route"):
+            cmd_b = float(costs.REDIRECT_CMD_BYTES)
+            red_cap = jnp.floor(budget_bytes / cmd_b).astype(jnp.int32)
+            cum = jnp.cumsum(sent, axis=1)
+            capped = jnp.maximum(
+                jnp.minimum(cum, red_cap[:, None]) - (cum - sent), 0)
+            kept = kept + jnp.sum(sent - capped, axis=1)
+            sent = capped
+            redirect_bytes = (jnp.sum(sent, axis=1).astype(jnp.float32)
+                              * cmd_b)
 
     # ---- topology-plane exchange (DESIGN.md §9/§11) ----------------------
     # Shard-local claims above already matched local lenders; only the
@@ -906,9 +911,10 @@ def _shard_step(cfg: EngineConfig, axis, state: EngineState,
         budget_bytes = budget_bytes + extra_link
 
     home_base = jnp.int32(0) if axis is None else jax.lax.axis_index(axis) * n
-    state = _admit(cfg, state, kept, sent, home_base=home_base,
-                   imported=imports, import_src=import_src,
-                   import_home=import_home)
+    with obs_x.scope("admit"):
+        state = _admit(cfg, state, kept, sent, home_base=home_base,
+                       imported=imports, import_src=import_src,
+                       import_home=import_home)
     key = jax.random.fold_in(jax.random.key(7), state.step_count)
     (state, active, attn_norm, spill_pages, offsite_after,
      quant_err) = _decode_all(cfg, state, dram_lenders, spill_budget, key)
@@ -940,7 +946,7 @@ def _shard_step(cfg: EngineConfig, axis, state: EngineState,
         "quant_err_norm": _pall(quant_err, axis),
     }
     if cfg.obs.enabled:
-        with jax.named_scope("obs_record"):
+        with obs_x.scope("obs_record"):
             base = (jnp.int32(0) if axis is None
                     else jax.lax.axis_index(axis) * n)
             ring_vals = dict(stats)
