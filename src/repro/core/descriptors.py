@@ -19,7 +19,7 @@ Descriptor layout (paper Fig. 7), one row per (node, slot):
 
 Resource types are *data*, not code forks: every rtype is described by a
 `ResourceSpec` in `REGISTRY` — its claim-score weights and its sync rules.
-`claim_best` and `sync_utilization` are generic loops over the registry, so
+`claim_score` and `sync_utilization` are generic over the registry, so
 adding a harvestable resource is one `register()` call plus a
 `manager.ResourcePolicy` entry (DESIGN.md §5); none of the publish/claim
 machinery changes. What an *assisted op* of each rtype costs (dequeue/
@@ -99,16 +99,6 @@ def spec_of(rtype: int) -> ResourceSpec:
     return REGISTRY[int(rtype)]
 
 
-def _score_weights() -> tuple[jax.Array, jax.Array]:
-    """Dense (score_a, score_b) weight tables indexed by rtype — what makes
-    `claim_best` a single vectorized expression for ANY registered rtype."""
-    top = max(REGISTRY) + 1
-    wa, wb = [0.0] * top, [0.0] * top
-    for r, s in REGISTRY.items():
-        wa[r], wb[r] = s.score_a, s.score_b
-    return jnp.asarray(wa, jnp.float32), jnp.asarray(wb, jnp.float32)
-
-
 class IdleResourceTable(NamedTuple):
     """Struct-of-arrays descriptor table, shape [n_nodes, n_slots]."""
 
@@ -181,54 +171,66 @@ def release(table: IdleResourceTable, borrower_id: jax.Array | int) -> IdleResou
     )
 
 
-def claimable_mask(
-    table: IdleResourceTable, borrower_id: jax.Array | int, rtype: jax.Array | int
-) -> jax.Array:
-    """[N, S] bool — valid, unclaimed, right type, and not our own node."""
-    node_ids = jnp.arange(table.n_nodes, dtype=jnp.int32)[:, None]
-    return (
-        table.valid
-        & (table.borrower_id == FREE)
-        & (table.rtype == jnp.int8(rtype))
-        & (node_ids != jnp.int32(borrower_id))
-    )
+def claim_score(
+    table: IdleResourceTable, rtype: int
+) -> tuple[jax.Array, jax.Array]:
+    """``(offers, score)``, bool/float32[N, S]: the valid descriptors of
+    ``rtype``, and each one's claim score by the rtype's registered weights
+    (`ResourceSpec`; -inf elsewhere). PROCESSOR prefers the lowest lender
+    utilization (amount_b), capacity types (DRAM, FLASH_BW, LINK_BW,
+    custom) the highest lendable amount_a. A claim moves only
+    ``borrower_id``, so both hold through every claim of a round."""
+    spec = spec_of(rtype)
+    offers = table.valid & (table.rtype == jnp.int8(rtype))
+    score = jnp.where(
+        offers, spec.score_a * table.amount_a + spec.score_b * table.amount_b,
+        -jnp.inf)
+    return offers, score
+
+
+def claim_one(
+    offers: jax.Array,
+    score: jax.Array,
+    borrower_ids: jax.Array,
+    borrower: jax.Array | int,
+    gate: jax.Array | bool = True,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The claim rule: if ``gate`` holds, ``borrower`` takes the
+    highest-scoring unclaimed offer that is not its own, ties broken by
+    lowest flat index. The write is a compare against an iota, not a
+    scatter, so a sweep of claims stays elementwise on the device.
+
+    Returns ``(borrower_ids', flat, success)``: the claimed flat index is
+    meaningful only where ``success`` (any claimable offer)."""
+    n, s = offers.shape
+    borrower = jnp.asarray(borrower, jnp.int32)
+    node_ids = jnp.arange(n, dtype=jnp.int32)[:, None]
+    mask = offers & (borrower_ids == FREE) & (node_ids != borrower)
+    flat = jnp.argmax(jnp.where(mask, score, -jnp.inf).reshape(-1))
+    success = jnp.any(mask)
+    hit = (jnp.arange(n * s, dtype=jnp.int32).reshape(n, s) == flat) \
+        & success & gate
+    return jnp.where(hit, borrower, borrower_ids), flat, success
 
 
 def claim_best(
     table: IdleResourceTable,
     borrower_id: jax.Array | int,
-    rtype: jax.Array | int,
+    rtype: int,
 ) -> tuple[IdleResourceTable, jax.Array, jax.Array, jax.Array]:
-    """Borrower atomically claims the best matching descriptor (workflow 3).
-
-    "Best" comes from the rtype's registered score weights (`ResourceSpec`):
-    PROCESSOR prefers the lowest lender utilization (amount_b), capacity
-    types (DRAM, FLASH_BW, LINK_BW, custom) the highest lendable amount_a.
-    The weight tables are indexed by each descriptor's rtype, so the score
-    is correct for every registered type — no two-way branch.
+    """Borrower atomically claims the best matching descriptor (workflow 3)
+    by `claim_one`, scored by `claim_score`.
 
     Returns (table', lender_id, slot, success). Under SPMD every replica
     computes the same argmax on the same replicated table, so the claim is
     race-free by determinism (ties broken by lowest flat index — stable).
     """
-    mask = claimable_mask(table, borrower_id, rtype)
-    wa, wb = _score_weights()
-    rt = jnp.clip(table.rtype.astype(jnp.int32), 0, wa.shape[0] - 1)
-    score = wa[rt] * table.amount_a + wb[rt] * table.amount_b
-    score = jnp.where(mask, score, -jnp.inf)
-    flat = jnp.argmax(score.reshape(-1))
-    success = jnp.any(mask)
-    lender = (flat // table.n_slots).astype(jnp.int32)
-    slot = (flat % table.n_slots).astype(jnp.int32)
-    new_borrower = jnp.where(
-        success, jnp.int32(borrower_id), table.borrower_id[lender, slot]
-    )
-    table = table._replace(
-        borrower_id=table.borrower_id.at[lender, slot].set(new_borrower)
-    )
-    lender = jnp.where(success, lender, -1)
-    slot = jnp.where(success, slot, -1)
-    return table, lender, slot, success
+    offers, score = claim_score(table, rtype)
+    bids, flat, success = claim_one(
+        offers, score, table.borrower_id, borrower_id)
+    lender = jnp.where(success, flat // table.n_slots, -1).astype(jnp.int32)
+    slot = jnp.where(success, flat % table.n_slots, -1).astype(jnp.int32)
+    return table._replace(borrower_id=bids), lender, slot, success
 
 
 def sync_utilization(

@@ -22,7 +22,7 @@ A round, per registered policy (see DESIGN.md §2):
   release     claims whose borrower no longer qualifies, and claims on
               withdrawn descriptors, drop to FREE
   claim       `claim_rounds` deterministic sweeps, busiest borrower first
-              (`jnp.argsort(-util)`, stable under ties), each sweep
+              (a stable sort on `-util`: ties by node id), each sweep
               claiming at most one lender per borrower up to `lender_cap`
   sync        `descriptors.sync_utilization` refreshes the amount fields
               per-rtype via the ResourceSpec registry
@@ -428,7 +428,13 @@ class ResourceManager:
     ) -> d.IdleResourceTable:
         """``claim_rounds`` sequential-deterministic sweeps, busiest borrower
         first ("most starved first"); each sweep a borrower claims its best
-        lender via `descriptors.claim_best`, capped at ``lender_cap``.
+        lender by `descriptors.claim_one`, capped at ``lender_cap``.
+
+        Claims move only ``borrower_id``, so the offers and their scores are
+        computed once and the sweeps carry ``borrower_id`` alone. One stable
+        sort gives the busiest-first order (ties by node id) and each
+        node's borrow flag in it, so the node step reads no table or vector
+        by index: it is compares, reductions and one argmax, O(N·S).
 
         Cap semantics (pinned by test_manager.py::
         test_lender_cap_counts_distinct_lenders_not_slots): ``have`` is the
@@ -439,26 +445,25 @@ class ResourceManager:
         lender's surplus), not a leak: total claimed slots are separately
         bounded by ``claim_rounds`` (at most one claim per sweep), and a
         borrower at the distinct-lender cap acquires nothing further."""
-        cap = jnp.int32(pol.lender_cap)
-        order = jnp.argsort(-util)  # stable: ties break by node id
+        offers, score = d.claim_score(table, pol.rtype)
+        node_ids = jnp.arange(table.n_nodes, dtype=jnp.int32)
+        _, order, wants = jax.lax.sort(
+            (-util, node_ids, borrow), num_keys=1, is_stable=True)
 
-        def node_body(tbl, node):
-            def do(tbl):
-                have = jnp.sum(d.lenders_of(tbl, node, pol.rtype))
-                tbl2, _, _, _ = d.claim_best(tbl, node, pol.rtype)
-                take = have < cap
-                return jax.tree.map(
-                    lambda a, b: jnp.where(take, b, a), tbl, tbl2
-                )
-            return jax.lax.cond(borrow[node], do, lambda t: t, tbl), None
+        def node_step(bids, x):
+            node, want = x
+            have = jnp.sum(jnp.any(offers & (bids == node), axis=1))
+            bids, _, _ = d.claim_one(
+                offers, score, bids, node, want & (have < pol.lender_cap))
+            return bids, None
 
-        def sweep(tbl, _):
-            tbl, _ = jax.lax.scan(node_body, tbl, order)
-            return tbl, None
+        def sweep(bids, _):
+            bids, _ = jax.lax.scan(node_step, bids, (order, wants))
+            return bids, None
 
-        table, _ = jax.lax.scan(
-            sweep, table, None, length=pol.claim_rounds)
-        return table
+        bids, _ = jax.lax.scan(
+            sweep, table.borrower_id, None, length=pol.claim_rounds)
+        return table._replace(borrower_id=bids)
 
     # ------------------------------------------------------------ derive
     def assist_matrix(
