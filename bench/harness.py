@@ -10,6 +10,24 @@ the plain reference (`bench/reference/`). Each per-layer metric is a reader
 of its own (`bench/metrics/<metric>.py`). A new cell, configuration, traffic
 mix or metric is a new file and a new entry, never an edit here.
 
+A new substrate (a system the benchmark drives) brings only new files:
+- `bench/substrates/<s>.py`: `Driver(cell, seed, devices, tracer)` with
+  `setup`, `run_window`, `check` and `layer_context`, and `small(cell)`,
+  which shrinks a loaded cell to a size a CPU test run holds
+  (`tests/bench/cpu_run.py`);
+- `bench/reference/<s>.py`: the plain reference `check` compares with;
+- its traffic generator, `bench/generators/<name>.py` with
+  `generate(params, ...)` (`traffic_gen.generator`), and its traffic mixes,
+  `bench/traffic/<traffic>.json`, whose `generator` key names it;
+- its work and roofline counts in a module of its own beside `work.py`
+  (never an edit to `work.py`);
+- its per-layer metrics' readers under `bench/metrics/`;
+- its control, `tests/bench/controls/<s>.py` with `in_place()`, which
+  `tests/bench/control.py` enters with every other.
+`tests/bench/test_bench_spec.py` holds every substrate file to this. The
+one edit to an existing entry a new cell needs is its name appended to the
+`workloads` list of each end-to-end metric it reports.
+
 Set-up (`setup_s`) runs from the process's start to the first timed step or
 call. With `--trace 0` the result line carries the cell's end-to-end
 metrics; with `--trace 1` the driver traces part of the window and the line
@@ -103,11 +121,17 @@ def enable_compile_cache() -> str:
 
 class CompileLog:
     """JAX's compile events with their host times, to count what compiles
-    inside the window and what tracing and compiling cost per call."""
+    inside the window and what tracing and compiling cost per call.
+
+    `compile_s` sums tracing, lowering and the backend compile. JAX times
+    the backend compile around its persistent-cache lookup, so on a cache
+    hit that event holds the load; `cache_load_s` reports the load apart
+    and is not added again."""
 
     KEYS = ("/jax/core/compile/jaxpr_trace_duration",
             "/jax/core/compile/jaxpr_to_mlir_module_duration",
             "/jax/core/compile/backend_compile_duration")
+    CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
 
     _instance = None
 
@@ -132,7 +156,8 @@ class CompileLog:
         self.events.append((time.perf_counter(), name, 0.0))
 
     def between(self, t0, t1):
-        out = {"compiles": 0, "cache_hits": 0, "compile_s": 0.0}
+        out = {"compiles": 0, "cache_hits": 0, "compile_s": 0.0,
+               "cache_load_s": 0.0}
         for t, name, secs in self.events:
             if not t0 <= t <= t1:
                 continue
@@ -140,6 +165,8 @@ class CompileLog:
                 out["compiles"] += 1
             elif name == "/jax/compilation_cache/cache_hits":
                 out["cache_hits"] += 1
+            elif name == self.CACHE_LOAD:
+                out["cache_load_s"] += secs
             elif name in self.KEYS:
                 out["compile_s"] += secs
         return out
@@ -199,15 +226,20 @@ def annotate(name: str):
     return jax.profiler.TraceAnnotation(name)
 
 
-def load_reader(metric: str):
-    path = BENCH / "metrics" / f"{metric}.py"
+def load_module(path: pathlib.Path, kind: str):
+    """The module in the file ``path``, a ``kind`` found by its name."""
     if not path.is_file():
-        raise FileNotFoundError(f"no reader for metric {metric!r} at {path}")
+        raise FileNotFoundError(f"no {kind} {path.stem!r} at {path}")
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_')}", path)
+        f"bench_{kind.replace(' ', '_')}_{path.stem.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(metric: str):
+    return load_module(BENCH / "metrics" / f"{metric}.py",
+                       "metric reader").read
 
 
 def memory_peak(devs) -> int | None:

@@ -50,6 +50,20 @@ TWIN_STEPS = 16
 TWIN_TOL = 1e-5
 DRAIN_MAX = 400
 SAMPLE_P = 1.0 / 16
+SMALL_ENGINE = dict(n_replicas=8, n_shards=2, pages_per_replica=16)
+SMALL_WIDTHS = dict(hidden_size=128, num_attention_heads=4,
+                    num_key_value_heads=2)
+# a quarter of the replicas: a quarter of the cells' 10 requests a step
+SMALL_REQUESTS_PER_STEP = 2.5
+
+
+def small(cell: dict) -> dict:
+    """The loaded ``cell`` shrunk so that a CPU test run holds it."""
+    conf = cell["config_file"]
+    conf["engine"].update(SMALL_ENGINE)
+    conf.update(SMALL_WIDTHS)
+    cell["traffic_file"]["requests_per_step"] = SMALL_REQUESTS_PER_STEP
+    return cell
 
 
 class Driver:
@@ -66,7 +80,7 @@ class Driver:
         self.operand = conf["matmul_operands"][devices[0].platform]
         self.limits = conf["limits"]
         tr = cell["traffic_file"]
-        self.traffic = traffic_gen.GENERATORS[tr["generator"]](
+        self.traffic = traffic_gen.generator(tr["generator"])(
             tr, self.cfg.n_replicas, seed)
         self.sample = traffic_gen.rng(seed, 5)
         self.attempted = self.failed = 0

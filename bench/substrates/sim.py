@@ -55,6 +55,13 @@ STATS = ("throughput_bps", "proc_util", "flash_util", "cxl_bytes",
 FLEET_STATS = ("throughput_bps", "flash_util", "proc_util")
 IDLE_STATS = ("throughput_bps", "flash_util")
 WARM_CALL = 2**31 - 1      # the set-up call's workload index
+SMALL_SIM = dict(n_enclosures=4, n_windows=60, warmup=20)
+
+
+def small(cell: dict) -> dict:
+    """The loaded ``cell`` shrunk so that a CPU test run holds it."""
+    cell["config_file"].update(SMALL_SIM)
+    return cell
 
 
 class Driver:
@@ -96,7 +103,7 @@ class Driver:
         self.info = {}
 
     def _workload(self, call):
-        gen = traffic_gen.GENERATORS[self.traffic["generator"]]
+        gen = traffic_gen.generator(self.traffic["generator"])
         with annotate("bench_traffic"):
             return gen(self.traffic, self.e, self.per, self.t, self.window_s,
                        self.seed, call)

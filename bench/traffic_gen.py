@@ -1,7 +1,9 @@
 """General traffic generators. A traffic mix is a JSON file under
-`bench/traffic/` whose `generator` key names one of the functions below and
-whose other keys are its parameters; everything is drawn from the run's
-seed, so the same seed gives the same traffic.
+`bench/traffic/` whose `generator` key names a generator (`generator`: one
+of `GENERATORS` below, or the `generate` function of
+`bench/generators/<name>.py`) and whose other keys are its parameters;
+everything is drawn from the run's seed, so the same seed gives the same
+traffic.
 """
 from __future__ import annotations
 
@@ -11,6 +13,7 @@ import pathlib
 import numpy as np
 
 TRAFFIC_DIR = pathlib.Path(__file__).resolve().parent / "traffic"
+GENERATORS_DIR = pathlib.Path(__file__).resolve().parent / "generators"
 BLOCK_STEPS = 512
 
 
@@ -123,3 +126,14 @@ def sim_bursts(params: dict, n_enclosures: int, per_enclosure: int,
 
 
 GENERATORS = {"engine_poisson": engine_poisson, "sim_bursts": sim_bursts}
+
+
+def generator(name: str):
+    """The generator a traffic mix names: the entry of `GENERATORS`, or
+    else the `generate` function of `generators/<name>.py`."""
+    if name in GENERATORS:
+        return GENERATORS[name]
+    from harness import load_module
+
+    return load_module(GENERATORS_DIR / f"{name}.py",
+                       "traffic generator").generate

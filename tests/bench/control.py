@@ -2,12 +2,10 @@
 
 The control is what the comparison has to refuse: the plain reference,
 computed in bfloat16, in the program's place (the nearest precision below
-the float32 that each configuration states):
-- engine cells: the K/V rows of the pool the window left, and the drain
-  steps' attention statistics, from the reference layer with every operation
-  rounded to bfloat16 (so its K/V are stored in bfloat16);
-- simulator cells: every `simulate` call's statistics from the reference
-  fluid model with every operation rounded to bfloat16.
+the float32 that each configuration states). Each substrate's control is a
+file of its own, `controls/<substrate>.py`, whose `in_place()` makes the
+substitution (the engine's K/V rows and attention statistics, the
+simulator's per-call statistics); `reference_in_place` enters them all.
 
     python tests/bench/control.py --workload engine.skew --variant control \
         --seeds 1 2 3 --seconds 3
@@ -25,6 +23,7 @@ import pathlib
 import sys
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
+CONTROLS = pathlib.Path(__file__).resolve().parent / "controls"
 for _p in (ROOT / "bench", ROOT / "src"):
     if str(_p) not in sys.path:
         sys.path.insert(0, str(_p))
@@ -83,57 +82,13 @@ class Rounded:
 def reference_in_place():
     """While active, a driver whose configuration carries `control_dtype`
     takes what it compares from the plain reference in that precision
-    instead of the program."""
-    with _engine_in_place(), _sim_in_place():
+    instead of the program: every control under `controls/` is in place."""
+    from harness import load_module
+
+    with contextlib.ExitStack() as stack:
+        for path in sorted(CONTROLS.glob("*.py")):
+            stack.enter_context(load_module(path, "control").in_place())
         yield
-
-
-@contextlib.contextmanager
-def _engine_in_place():
-    from substrates import engine as drv
-
-    orig = drv.Driver._compare
-
-    def compare(self, w, window_rows, drains):
-        dt = self.cfg_file.get("control_dtype")
-        if dt is not None:
-            ctl = self.reference_layer(w, cast=Rounded(dt), operand=dt)
-            window_rows = [(t, r, *ctl.kv_row(t, r))
-                           for t, r, _, _ in window_rows]
-            drains = [(t, att, ctl.attn_norm(t, att))
-                      for t, att, _ in drains]
-        return orig(self, w, window_rows, drains)
-
-    drv.Driver._compare = compare
-    try:
-        yield
-    finally:
-        drv.Driver._compare = orig
-
-
-@contextlib.contextmanager
-def _sim_in_place():
-    import numpy as np
-    from reference import sim as ref
-    from substrates import sim as drv
-
-    orig = drv.Driver._call
-
-    def call(self, rows, arr):
-        dt = self.conf.get("control_dtype")
-        if dt is None:
-            return orig(self, rows, arr)
-        out = ref.simulate(self.params, rows, arr, self.e, self.warmup,
-                           self.window_s, cast=Rounded(dt))
-        out["ring_borrowed"] = out["ring_borrowed"][:, None]
-        out["ring_spare"] = out["ring_spare"][:, None]
-        return {k: np.asarray(v, np.float64) for k, v in out.items()}
-
-    drv.Driver._call = call
-    try:
-        yield
-    finally:
-        drv.Driver._call = orig
 
 
 def readings(workload: str, seeds, seconds: float, variant: str):

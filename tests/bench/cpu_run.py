@@ -1,12 +1,14 @@
 """Drive a benchmark cell through the harness on the host CPU at a small
 size: the look for a chip is skipped, the peaks of the chip the cell is
 measured on stand in for the CPU's, and the configuration and traffic are
-shrunk so that a test run holds them. Everything after the look for a chip
-runs as in a chip run: set-up, the measured window, the check against the
-plain reference and the result line."""
+shrunk, by the `small(cell)` hook of the cell's substrate, so that a test
+run holds them. Everything after the look for a chip runs as in a chip run:
+set-up, the measured window, the check against the plain reference and the
+result line."""
 from __future__ import annotations
 
 import contextlib
+import importlib
 import io
 import json
 import pathlib
@@ -20,22 +22,16 @@ for p in (ROOT / "bench", ROOT / "src"):
 import harness  # noqa: E402
 import peaks  # noqa: E402
 
-SMALL_ENGINE = dict(n_replicas=8, n_shards=2, pages_per_replica=16)
-SMALL_WIDTHS = dict(hidden_size=128, num_attention_heads=4,
-                    num_key_value_heads=2)
-SMALL_SIM = dict(n_enclosures=4, n_windows=60, warmup=20)
-
-
 def shrink(cell: dict) -> dict:
-    conf = cell["config_file"]
-    if conf["substrate"] == "engine":
-        conf["engine"].update(SMALL_ENGINE)
-        conf.update(SMALL_WIDTHS)
-        # a quarter of the replicas: a quarter of the offered load
-        cell["traffic_file"]["requests_per_step"] = 2.5
-    else:
-        conf.update(SMALL_SIM)
-    return cell
+    """The loaded ``cell`` at the small size of its substrate's hook."""
+    sub = cell["config_file"]["substrate"]
+    small = getattr(importlib.import_module(f"substrates.{sub}"), "small",
+                    None)
+    if small is None:
+        raise NotImplementedError(
+            f"substrate {sub!r} defines no small(cell) in "
+            f"bench/substrates/{sub}.py")
+    return small(cell)
 
 
 def run(monkeypatch, workload: str, seed: int, seconds: float = 1.0,

@@ -1,5 +1,8 @@
 """BENCHMARK.json is well formed, and every cell resolves its
-configuration, traffic mix, driver, reference and metric readers by name."""
+configuration, traffic mix and generator, driver, reference, control and
+metric readers by name; every substrate, used by a cell or not, brings the
+files `bench/harness.py` lists."""
+import importlib
 import importlib.util
 import json
 import pathlib
@@ -12,11 +15,29 @@ ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT / "bench"), str(ROOT / "src")]
 
 import harness  # noqa: E402
+import traffic_gen  # noqa: E402
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 CELLS = [w["name"] for w in SPEC["workloads"]]
+SUBSTRATES = sorted(p.stem for p in (ROOT / "bench" / "substrates")
+                    .glob("*.py") if not p.stem.startswith("_"))
+DRIVER_METHODS = ("setup", "run_window", "check", "layer_context")
+
+
+def hold_to_the_contract(substrate: str):
+    """The files a substrate brings (`bench/harness.py`'s docstring)."""
+    mod = importlib.import_module(f"substrates.{substrate}")
+    for name in DRIVER_METHODS:
+        assert callable(getattr(getattr(mod, "Driver", None), name, None)), \
+            f"substrates.{substrate}.Driver has no {name}"
+    assert callable(getattr(mod, "small", None)), \
+        f"substrates.{substrate} defines no small(cell)"
+    assert (ROOT / "bench" / "reference" / f"{substrate}.py").is_file()
+    control = harness.load_module(
+        ROOT / "tests" / "bench" / "controls" / f"{substrate}.py", "control")
+    assert callable(control.in_place)
 
 
 def test_top_level_keys_and_paths():
@@ -66,12 +87,17 @@ def test_configuration_files_state_what_is_reduced():
         assert conf["assumed"] and conf["limits"]
 
 
+@pytest.mark.parametrize("substrate", SUBSTRATES)
+def test_every_substrate_holds_the_contract(substrate):
+    hold_to_the_contract(substrate)
+
+
 @pytest.mark.parametrize("cell", CELLS)
 def test_every_cell_resolves_by_name(cell):
     c = harness.load_cell(cell)
-    assert c["config_file"]["substrate"] in ("engine", "sim")
+    hold_to_the_contract(c["config_file"]["substrate"])
     assert c["chips"] in (1, 4)
-    assert c["traffic_file"]["generator"]
+    assert callable(traffic_gen.generator(c["traffic_file"]["generator"]))
     e2e = [m["name"] for m in c["end_to_end"]]
     assert "setup_s" in e2e and len(e2e) >= 2
     assert c["per_layer"], f"{cell} reports no per-layer metric"
