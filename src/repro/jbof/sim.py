@@ -991,6 +991,15 @@ def _init_state(plat: Platform, n: int,
     )
 
 
+def _enclosure_state(plat: Platform, e: int, nl: int,
+                     tcfg: tele_win.TelemetryConfig,
+                     obs: obs_m.ObsConfig) -> SimState:
+    """`e` copies of one enclosure's `_init_state` on a leading axis: one
+    broadcast a leaf, so the host's dispatches do not grow with `e`."""
+    return jax.tree.map(lambda a: jnp.broadcast_to(a, (e,) + a.shape),
+                        _init_state(plat, nl, tcfg, obs))
+
+
 @dataclasses.dataclass(frozen=True)
 class SimConfig:
     """One frozen bundle for every `simulate` run knob.
@@ -1133,8 +1142,7 @@ def simulate(
                 raise ValueError(
                     f"n_enclosures={e} must divide the {n} SSDs evenly")
             nl = n // e
-            st0 = jax.tree.map(
-                lambda a: jnp.stack([a] * e), _init_state(plat, nl, tcfg, obs))
+            st0 = _enclosure_state(plat, e, nl, tcfg, obs)
             wv_e = jax.tree.map(lambda a: a.reshape(e, nl), wv)
             wf_e = want_frac.reshape(e, nl)
             xg0 = FabricIn(*(jnp.zeros((e,), jnp.float32) for _ in range(4)))

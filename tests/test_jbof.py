@@ -1,9 +1,14 @@
 """System tests for the JBOF simulator: paper-claim reproduction bands +
 conservation/sanity properties."""
+import jax
+import jax.numpy as jnp
 import numpy as np
+import pytest
+from jax._src import core as jax_core
 
 from repro.core import harvest as hv
 from repro.jbof import bom, platforms, sim, ssd, workloads as wl
+from repro.obs import metrics as obs_m
 
 
 def _run(plat_name, wls, n=300, seed=0, **kw):
@@ -296,3 +301,73 @@ class TestMultiEnclosure:
         dear = self._split(fabric_extra_hops=256.0)
         assert (float(np.asarray(cheap.latency_s[:6]).mean())
                 <= float(np.asarray(dear.latency_s[:6]).mean()) + 1e-9)
+
+
+def _fleet(e, obs=False, per=4, windows=12):
+    """`e` enclosures of `per` SSDs, half busy and half idle in each."""
+    wls = ([BUSY] * (per // 2) + [wl.idle()] * (per - per // 2)) * e
+    arr = wl.arrivals(wls, windows, seed=3)
+    return wls, arr, sim.SimConfig(n_enclosures=e, warmup=2,
+                                   obs=obs_m.ObsConfig(enabled=obs))
+
+
+def _eager_binds(monkeypatch, fn):
+    """Primitives bound outside any trace while `fn` runs: each is one
+    eager dispatch from the host."""
+    count, bind = [0], jax_core.Primitive.bind
+
+    def counting(self, *args, **params):
+        if isinstance(jax_core.trace_ctx.trace, jax_core.EvalTrace):
+            count[0] += 1
+        return bind(self, *args, **params)
+
+    with monkeypatch.context() as m:
+        m.setattr(jax_core.Primitive, "bind", counting)
+        fn()
+    return count[0]
+
+
+@pytest.mark.parametrize("obs", [False, True], ids=["plain", "obs"])
+def test_simulate_dispatches_do_not_grow_with_enclosures(monkeypatch, obs):
+    """The host's eager dispatches over one `simulate` call are the same at
+    4 and at 32 enclosures of 4 SSDs: nothing is built per enclosure."""
+    counts = []
+    for e in (4, 32):
+        wls, arr, cfg = _fleet(e, obs)
+        counts.append(_eager_binds(
+            monkeypatch,
+            lambda: sim.simulate(platforms.xbof(), wls, arr, cfg)))
+    assert counts[0] == counts[1], counts
+
+
+def _stacked_state(plat, e, nl, tcfg, obs):
+    """The initial carry as `e` stacked copies of one enclosure's."""
+    return jax.tree.map(lambda a: jnp.stack([a] * e),
+                        sim._init_state(plat, nl, tcfg, obs))
+
+
+def _assert_trees_equal(got, want):
+    g, gdef = jax.tree.flatten(got)
+    w, wdef = jax.tree.flatten(want)
+    assert gdef == wdef
+    for a, b in zip(g, w):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("obs", [False, True], ids=["plain", "obs"])
+def test_enclosure_carry_equals_stacked_copies(monkeypatch, obs):
+    """The broadcast initial carry equals `e` stacked copies leaf by leaf
+    (values, dtypes, shapes), and `simulate` at 4 enclosures returns the
+    same statistics, rings and obs feed from either."""
+    plat, ocfg = platforms.xbof(), obs_m.ObsConfig(enabled=obs)
+    for tcfg in (sim._NO_TELEMETRY, sim.SIM_TELEMETRY):
+        _assert_trees_equal(sim._enclosure_state(plat, 4, 3, tcfg, ocfg),
+                            _stacked_state(plat, 4, 3, tcfg, ocfg))
+    wls, arr, cfg = _fleet(4, obs)
+    got = sim.simulate(plat, wls, arr, cfg)
+    with monkeypatch.context() as m:
+        m.setattr(sim, "_enclosure_state", _stacked_state)
+        want = sim.simulate(plat, wls, arr, cfg)
+    _assert_trees_equal(got, want)
